@@ -62,8 +62,13 @@ class LayerCost:
 
     @property
     def serial_latency(self) -> float:
-        """Serial (no-overlap) latency: the left-to-right sum."""
-        return sum(o.latency for o in self.ops)
+        """Serial (no-overlap) latency: the left-to-right sum, rounded as
+        `Schedule.serial` rounds it (the builtin `sum` compensates its
+        rounding since Python 3.12, and would differ in the last ulp)."""
+        total = 0.0
+        for o in self.ops:
+            total += o.latency
+        return total
 
     @property
     def flops(self) -> float:

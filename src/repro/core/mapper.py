@@ -48,7 +48,6 @@ re-reads previous sessions' searches instead of re-solving them.
 from __future__ import annotations
 
 import os
-import warnings
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Any, ClassVar, Dict, List, Optional, Sequence, Tuple, cast
@@ -380,7 +379,8 @@ def _chunk_tables(g: Dict[str, Any]) -> Dict[str, Any]:
     much of the dense-equivalent search was actually paid for."""
     _REG.inc("mapper.rows_evaluated", float(g["tm"].size))
     if _BACKEND == "jax":
-        return _jax_tables(g)
+        from . import mapper_jax
+        return mapper_jax.chunk_tables(g)
     return _chunk_tables_numpy(g)
 
 
@@ -432,21 +432,6 @@ def _solve_chunk(devs: Sequence[Device], shapes: Sequence[MatmulShape],
     won = _pick_winners(g, tables, [devs[j] for j in first],
                         [shapes[j] for j in first])
     return [won[o] for o in owner]
-
-
-def _jax_tables(g: Dict[str, Any]) -> Dict[str, Any]:
-    """Dispatch to the JAX backend, falling back to numpy (once, loudly)
-    when jax is unavailable in this environment."""
-    global _BACKEND
-    try:
-        from . import mapper_jax
-    except Exception as e:        # jax missing or broken: degrade, keep going
-        warnings.warn(f"mapper backend 'jax' unavailable ({e}); "
-                      f"falling back to numpy", RuntimeWarning,
-                      stacklevel=3)
-        _BACKEND = "numpy"
-        return _chunk_tables_numpy(g)
-    return mapper_jax.chunk_tables(g)
 
 
 # candidate-row budget per broadcast chunk (~25 work arrays x 8B x rows).

@@ -1,5 +1,10 @@
 """JAX backend for the mapper's chunk evaluation (ISSUE 6).
 
+Host code: the kernel computes in int64/float64, which the TPU has no
+native units for, so it is always placed on the host CPU
+(`jax.devices("cpu")[0]`) and never takes an accelerator, even in a
+process that holds one.
+
 The compressed candidate search is embarrassingly data-parallel: every
 feasible (tile, subtile, pipeline) row of a chunk is priced independently by
 ~30 elementwise int64/float64 ops. This module evaluates those rows with one
@@ -19,7 +24,7 @@ cannot change any surviving row's total. Dtype mix (int64 byte
 widths vs float64 sub-byte widths) keys its own trace, exactly mirroring the
 numpy path's dtype promotion rule.
 
-Numerics: the kernel runs under `jax.experimental.enable_x64` so every
+Numerics: the kernel runs under `jax.enable_x64` so every
 intermediate matches the numpy path's dtype (int64 ceil-divisions are exact;
 float64 elementwise ops are IEEE). There are no reductions anywhere in the
 table computation, so XLA cannot reassociate sums; the one documented
@@ -38,7 +43,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from .systolic import gemm_cycles_array
 
@@ -188,7 +192,7 @@ def chunk_tables(g: Dict) -> Dict:
     p_ok[:n] = g["p_ok"]
     padded["p_ok"] = p_ok
 
-    with enable_x64():
+    with jax.enable_x64(True), jax.default_device(jax.devices("cpu")[0]):
         out = jax.device_get(_tables_kernel(padded))
     return {k: v[:n] for k, v in out.items()}
 

@@ -33,6 +33,10 @@ class RankedPlan:
     fits: bool
 
 
+class NoFittingPlan(ValueError):
+    """No plan of the system holds the model in device memory."""
+
+
 def _divisors(n: int) -> List[int]:
     return [d for d in range(1, n + 1) if n % d == 0]
 
@@ -116,7 +120,7 @@ def best_plan(system: System, cfg: ModelConfig, batch: int, in_len: int,
                         evaluator=evaluator, policy=policy, fusion=fusion)
     fitting = [r for r in ranked if r.fits]
     if not fitting:
-        raise ValueError(
+        raise NoFittingPlan(
             f"{cfg.name} does not fit on {system.device_count}x"
             f"{system.device.name} under any plan")
     return fitting[0]

@@ -30,6 +30,9 @@ from __future__ import annotations
 import csv
 import io
 import math
+import multiprocessing
+import os
+import sys
 import time
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass
@@ -533,7 +536,12 @@ class Study:
                       for w in range(workers)]
         payloads = [([self.cases[i] for i in sh],) + common
                     for sh in idx_shards]
-        with ProcessPoolExecutor(max_workers=workers) as pool:
+        # spawn, not fork: a forked child would inherit a parent's JAX
+        # runtime (and with it any accelerator the parent holds)
+        with ProcessPoolExecutor(max_workers=workers,
+                                 mp_context=multiprocessing.get_context(
+                                     "spawn"),
+                                 initializer=_host_only_worker) as pool:
             outs = list(pool.map(_study_worker, payloads))
 
         results: List[Optional[CaseResult]] = [None] * len(self.cases)
@@ -757,13 +765,25 @@ class Study:
         return att, crit
 
 
+def _host_only_worker() -> None:
+    """Initializer of every `Study.run(workers=N)` shard process. The
+    evaluator is host code: pin any JAX the shard imports (the mapper's
+    optional backend) to the CPU, so a shard never takes the accelerator
+    of the process that started it. A parent `__main__` re-imported by
+    spawn may already have imported jax; its backends are not up yet, so
+    the config update still takes."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def _study_worker(payload: Tuple[Any, ...]) -> Tuple[Any, ...]:
     """Entry point of one `Study.run(workers=N)` shard process.
 
     The parent ships its resolved configuration explicitly (cache root +
     enabled flag, mapper backend and prune mode, verify mode, phase-span
-    switch) rather than relying on inherited globals, so shards behave
-    identically under fork and spawn start methods — runtime overrides like
+    switch) rather than relying on inherited globals, since shards start
+    by spawn from a fresh import — runtime overrides like
     `result_cache.overridden(root=...)` are re-applied here. The shard runs
     as a plain serial Study (its own Evaluators, its own case-cache
     lookups) and returns its ordered CaseResults plus the stats and the
@@ -772,10 +792,7 @@ def _study_worker(payload: Tuple[Any, ...]) -> Tuple[Any, ...]:
      cache_root, cache_enabled, spans) = payload
     from . import mapper
     result_cache_mod.configure(root=cache_root, enabled=cache_enabled)
-    try:
-        mapper.set_mapper_backend(backend)
-    except ImportError:                 # jax missing in the child: degrade
-        mapper.set_mapper_backend("numpy")
+    mapper.set_mapper_backend(backend)
     mapper.set_mapper_prune(prune)
     reg = obs.metrics()
     reg.set_enabled(spans)

@@ -6,9 +6,12 @@ One query token per sequence; the kernel streams KV blocks from HBM through
 VMEM exactly once per kv-head (GQA: the G query heads of a group ride the
 same KV stream). q lives in VMEM for the whole sweep.
 
-Layouts: q (B, Hkv, G, D); k/v (B, T, Hkv, D); lengths (B,) valid KV
-lengths (ring-buffer caches pass full T). Grid (b, h, ki), ki innermost;
-running (m, l, acc) in VMEM scratch.
+Layouts: q (B, Hkv, G, D); k/v fused (B, T, Hkv*D) — the serving cache's
+own layout, so kv-head h is the lane-aligned column block h of width D and
+each KV block is a (bk, D) tile (a (.., 1, D) block over a (Hkv, D) minor
+pair is not a legal TPU tiling); lengths (B,) valid KV lengths
+(ring-buffer caches pass full T). Grid (b, h, ki), ki innermost; running
+(m, l, acc) in VMEM scratch.
 """
 from __future__ import annotations
 
@@ -36,7 +39,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     valid = len_ref[b]
     q = q_ref[0, 0].astype(jnp.float32)              # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (bk, D)
+    k = k_ref[0].astype(jnp.float32)                 # (bk, D)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G, bk)
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
@@ -48,7 +51,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
     m_ref[...] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32)           # (bk, D)
+    v = v_ref[0].astype(jnp.float32)                 # (bk, D)
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
 
@@ -60,9 +63,10 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
 def decode_attention_pallas(q, k, v, lengths, *, softcap: float = 0.0,
                             bk: int = 512, interpret: bool = False):
-    """q: (B, Hkv, G, D); k, v: (B, T, Hkv, D); lengths: (B,) int32."""
+    """q: (B, Hkv, G, D); k, v: (B, T, Hkv*D); lengths: (B,) int32."""
     B, Hkv, G, D = q.shape
-    _, T, _, _ = k.shape
+    T = k.shape[1]
+    assert k.shape == v.shape == (B, T, Hkv * D), (q.shape, k.shape)
     bk = min(bk, T)
     grid = (B, Hkv, pl.cdiv(T, bk))
     kern = functools.partial(_decode_kernel, n_k=grid[2], bk=bk,
@@ -73,8 +77,8 @@ def decode_attention_pallas(q, k, v, lengths, *, softcap: float = 0.0,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # lengths, whole array
             pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, j: (b, j, h, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, h, j: (b, j, h)),
+            pl.BlockSpec((1, bk, D), lambda b, h, j: (b, j, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),

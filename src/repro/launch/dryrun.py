@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 cell on the production meshes and record memory / FLOPs / collective bytes.
 
@@ -12,9 +13,10 @@ roofline table (EXPERIMENTS.md §Roofline) is generated from these files by
 launch/roofline_report.py. Cells already on disk are skipped unless
 --force.
 
-The FIRST TWO LINES of this file must stay first: jax locks the device
+The FIRST THREE LINES of this file must stay first: jax locks the device
 count at first init, and the dry-run (and only the dry-run) needs 512
-placeholder CPU devices.
+placeholder CPU devices. It pins the CPU backend so that, on a machine
+with an accelerator, it never takes the chip.
 """
 import argparse
 import json

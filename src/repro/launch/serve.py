@@ -7,7 +7,11 @@ engine.
 The planner (the paper's performance model) is consulted first: it prints
 the predicted-latency-optimal (tp, pp, dp) plan and predicted throughput
 for the target system before the engine starts — Sec. IV of the paper used
-as a deployment tool.
+as a deployment tool. Those are predictions for the described v5e pod, not
+for the device the engine runs on.
+
+`main(argv)` returns the Engine and its served requests, so a caller
+(chip_smoke.py) drives this same entry point and inspects the result.
 """
 from __future__ import annotations
 
@@ -21,22 +25,27 @@ from .. import models
 from ..core import hardware as hw
 from ..core import planner
 from ..serving import Engine, Request, SamplingParams
+from . import compile_cache
 from .train import preset_config
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--preset", choices=["tiny", "m100", "full"],
                     default="tiny")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--max-new", type=int, default=16,
+                    help="tokens per request; odd-numbered requests ask for "
+                         "half, so slots free at different steps and are "
+                         "refilled while others decode")
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--plan-chips", type=int, default=16,
                     help="v5e chips for the planning report")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
 
     full_cfg = get_config(args.arch)
     # 1) plan on the real config with the paper's model
@@ -50,7 +59,7 @@ def main():
               f"pred latency={best.latency * 1e3:.1f}ms  "
               f"pred throughput={best.throughput:.0f} tok/s  "
               f"mem/chip={best.memory_per_device / 2 ** 30:.2f}GiB")
-    except ValueError as e:
+    except planner.NoFittingPlan as e:
         print(f"[planner] {e}")
 
     # 2) serve the (preset) model locally
@@ -60,7 +69,8 @@ def main():
     sampling = SamplingParams(temperature=args.temperature, top_k=40)
     reqs = [Request(uid=i, prompt=[(7 * i + j) % cfg.vocab_size
                                    for j in range(5 + i % 7)],
-                    max_new_tokens=args.max_new, sampling=sampling)
+                    max_new_tokens=max(1, args.max_new >> (i % 2)),
+                    sampling=sampling)
             for i in range(args.requests)]
     t0 = time.perf_counter()
     done = eng.run(reqs)
@@ -69,6 +79,7 @@ def main():
         print(f"req {r.uid}: prompt={r.prompt} -> {r.output}")
     print(f"served {len(done)} requests, {eng.stats['tokens_out']} tokens "
           f"in {dt:.2f}s ({eng.throughput():.1f} tok/s decode-side)")
+    return eng, done
 
 
 if __name__ == "__main__":

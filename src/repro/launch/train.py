@@ -24,6 +24,7 @@ from ..data import DataConfig, TokenPipeline
 from ..distributed import sharding as shd
 from ..distributed.fault_tolerance import RestartManifest, StepMonitor
 from ..training import AdamW, cosine_schedule, init_state, make_train_step
+from . import compile_cache
 from .mesh import make_host_mesh
 
 
@@ -59,6 +60,7 @@ def main():
     ap.add_argument("--token-file", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = preset_config(get_config(args.arch), args.preset)
     mesh = make_host_mesh(data=args.data, model=args.model)

@@ -53,10 +53,7 @@ def _activation_spec(x):
     d_model over model — keeps the remat-saved unit boundaries sharded
     instead of replicated (a beyond-paper optimization, EXPERIMENTS §Perf).
     Applies only under an active mesh whose axes divide the dims."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except AttributeError:      # older jax
-        return None
+    am = jax.sharding.get_abstract_mesh()
     if am is None or not am.shape:
         return None
     from jax.sharding import PartitionSpec as P

@@ -41,6 +41,16 @@ class Request:
     done: bool = False
 
 
+def jit_steps(cfg: ModelConfig):
+    """The engine's two device programs, jitted: prefill(params, tokens,
+    cache, prompt_lens, frontend) and decode(params, token, cache)."""
+    prefill = jax.jit(
+        lambda p, t, c, l, f: models.prefill(cfg, p, t, c, frontend=f,
+                                             prompt_lens=l))
+    decode = jax.jit(lambda p, t, c: models.decode_step(cfg, p, t, c))
+    return prefill, decode
+
+
 class Engine:
     def __init__(self, cfg: ModelConfig, params, batch_size: int,
                  max_len: int, seed: int = 0, policy: str = "continuous"):
@@ -51,14 +61,11 @@ class Engine:
         self.key = jax.random.PRNGKey(seed)
         self.cache = models.init_cache(cfg, batch_size, max_len)
         self.sched = SlotScheduler(batch_size, policy=policy)
-        self._prefill = jax.jit(
-            lambda p, t, c, l, f: models.prefill(cfg, p, t, c, frontend=f,
-                                                 prompt_lens=l))
-        self._decode = jax.jit(
-            lambda p, t, c: models.decode_step(cfg, p, t, c))
+        self._prefill, self._decode = jit_steps(cfg)
         self._insert = jax.jit(self._insert_impl, static_argnames=("slot",))
+        # waves: whole-batch prefills; refills: per-slot prefill + insert
         self.stats = {"tokens_out": 0, "prefill_s": 0.0, "decode_s": 0.0,
-                      "steps": 0}
+                      "steps": 0, "waves": 0, "refills": 0}
 
     @property
     def slot_req(self) -> List[Optional[Request]]:
@@ -109,6 +116,7 @@ class Engine:
                 np.int32)
             for i, r in enumerate(wave):
                 self._admit_slot(i, r, int(first[i]))
+            self.stats["waves"] += 1
         else:
             # per-slot insertion
             for slot, r in pairs:
@@ -121,6 +129,7 @@ class Engine:
                 self.key, sub = jax.random.split(self.key)
                 first = sample_per_request(logits[:1], sub, [r.sampling])
                 self._admit_slot(slot, r, int(np.asarray(first[0])))
+                self.stats["refills"] += 1
         self.stats["prefill_s"] += time.perf_counter() - t0
         return wave
 

@@ -197,3 +197,40 @@ def test_trace_reuse_across_chunk_sizes():
     _solve_with("jax", DEVICES[0], SHAPES[:3])
     _solve_with("jax", DEVICES[0], SHAPES[:5])
     assert mapper_jax._tables_kernel._cache_size() <= sizes + 2
+
+
+def test_unimportable_jax_backend_raises_instead_of_degrading(monkeypatch):
+    """REPRO_MAPPER_BACKEND=jax picks the backend at import without
+    importing it; if mapper_jax then cannot be imported, the first chunk
+    evaluation raises: no silent switch to numpy."""
+    from repro import core
+    from repro.core import mapper
+    monkeypatch.setattr(mapper, "_BACKEND", "jax")      # as the env var does
+    monkeypatch.setitem(sys.modules, "repro.core.mapper_jax", None)
+    monkeypatch.delattr(core, "mapper_jax", raising=False)
+    with pytest.raises(ImportError):
+        matmul_perf_batch(DEVICES[0], SHAPES[:1])
+    assert mapper.get_mapper_backend() == "jax"
+
+
+def test_jax_backend_runs_on_the_host_cpu(monkeypatch):
+    """The kernel computes in int64/float64: it is host code and is placed
+    on the CPU even where an accelerator is the default device."""
+    import numpy as np
+    from repro.core import mapper_jax
+    g = {c: np.ones(1, np.int64) for c in mapper_jax._INT_COLS}
+    g.update({c: np.ones(1) for c in mapper_jax._FLT_COLS})
+    g.update({c: np.full(1, 2, np.int64) for c in mapper_jax._DYN_COLS})
+    g["b_shared"] = np.zeros(1, bool)
+    g["p_ok"] = np.ones((1, 4), bool)
+    seen = []
+    kernel = mapper_jax._tables_kernel
+
+    def spy(padded):
+        seen.append(jax.config.jax_default_device)
+        return kernel(padded)
+
+    monkeypatch.setattr(mapper_jax, "_tables_kernel", spy)
+    out = mapper_jax.chunk_tables(g)
+    assert seen == [jax.devices("cpu")[0]]
+    assert out["totals"].shape == (1, 4)

@@ -101,10 +101,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     shd.set_model_config(cfg)
     key = jax.random.PRNGKey(0)
     opt = AdamW(lr=constant_schedule(1e-3))
-    # jax<0.6 has no jax.sharding.set_mesh; Mesh itself is a context manager
-    _set_mesh = getattr(jax.sharding, "set_mesh", None) \
-        or getattr(jax.sharding, "use_mesh", None) or (lambda m: m)
-    with _set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         state = init_state(cfg, opt, key)
         abs_p = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state.params)
         p_shard = shd.param_shardings(mesh, abs_p)

@@ -37,7 +37,7 @@ def _grid_study(**kw):
 
 
 def _run(workers, **kw):
-    clear_matmul_cache()        # workers fork: don't inherit a warm memo
+    clear_matmul_cache()        # the serial side starts cold too
     return _grid_study(**kw).run(workers=workers)
 
 
@@ -98,6 +98,34 @@ def test_parallel_warm_rerun_hits_case_cache():
 def test_workers_zero_and_one_are_serial():
     with result_cache.disabled():
         assert _run(0).to_rows() == _run(1).to_rows() == _run(None).to_rows()
+
+
+def test_jax_backend_through_spawned_workers():
+    """Shards start by spawn with JAX pinned to the CPU; the mapper's JAX
+    backend then runs inside them and rows match its serial run."""
+    from repro.core.mapper import set_mapper_backend
+    with result_cache.disabled():
+        prev = set_mapper_backend("jax")
+        try:
+            serial = _run(None)
+            par = _run(2)
+        finally:
+            set_mapper_backend(prev)
+            clear_matmul_cache()    # the memo key has no backend
+    assert par.to_rows() == serial.to_rows()
+
+
+def test_worker_initializer_pins_jax_to_cpu(monkeypatch):
+    import jax
+    from repro.core.study import _host_only_worker
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    prev = jax.config.jax_platforms
+    try:
+        _host_only_worker()
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+        assert jax.config.jax_platforms == "cpu"
+    finally:
+        jax.config.update("jax_platforms", prev)
 
 
 def test_negative_workers_raises():
