@@ -78,7 +78,7 @@ def main(argv=None):
     for r in done[: min(4, len(done))]:
         print(f"req {r.uid}: prompt={r.prompt} -> {r.output}")
     print(f"served {len(done)} requests, {eng.stats['tokens_out']} tokens "
-          f"in {dt:.2f}s ({eng.throughput():.1f} tok/s decode-side)")
+          f"in {dt:.2f}s wall, {eng.stats['tokens_out'] / dt:.1f} tok/s")
     return eng, done
 
 

@@ -13,16 +13,22 @@ Slot model (vLLM-style, static shapes for XLA):
 
 Recurrent/hybrid archs (state pollution from right pads) are admitted in
 equal-length buckets — the scheduler handles that transparently.
+
+Each call's pieces run inside `jax.profiler.TraceAnnotation` spans named
+`engine.*` (plan, wave, refill, decode; inside them cache, feed, prefill,
+step, insert, sample, wait, commit). They land in the profiler's own trace,
+on the device planes' clock, and record nothing while no trace runs.
+`engine.wait` holds only the host blocking on the device.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from ..configs.base import ModelConfig
 from ..core.scheduler import SlotScheduler
@@ -43,12 +49,17 @@ class Request:
 
 def jit_steps(cfg: ModelConfig):
     """The engine's two device programs, jitted: prefill(params, tokens,
-    cache, prompt_lens, frontend) and decode(params, token, cache)."""
-    prefill = jax.jit(
-        lambda p, t, c, l, f: models.prefill(cfg, p, t, c, frontend=f,
-                                             prompt_lens=l))
-    decode = jax.jit(lambda p, t, c: models.decode_step(cfg, p, t, c))
-    return prefill, decode
+    cache, prompt_lens, frontend) and decode(params, token, cache). Named
+    functions, so the device trace names them `jit_prefill_step` and
+    `jit_decode_step`."""
+    def prefill_step(params, tokens, cache, prompt_lens, frontend):
+        return models.prefill(cfg, params, tokens, cache, frontend=frontend,
+                              prompt_lens=prompt_lens)
+
+    def decode_step(params, token, cache):
+        return models.decode_step(cfg, params, token, cache)
+
+    return jax.jit(prefill_step), jax.jit(decode_step)
 
 
 class Engine:
@@ -64,8 +75,8 @@ class Engine:
         self._prefill, self._decode = jit_steps(cfg)
         self._insert = jax.jit(self._insert_impl, static_argnames=("slot",))
         # waves: whole-batch prefills; refills: per-slot prefill + insert
-        self.stats = {"tokens_out": 0, "prefill_s": 0.0, "decode_s": 0.0,
-                      "steps": 0, "waves": 0, "refills": 0}
+        self.stats = {"tokens_out": 0, "steps": 0, "waves": 0,
+                      "refills": 0}
 
     @property
     def slot_req(self) -> List[Optional[Request]]:
@@ -93,44 +104,58 @@ class Engine:
     # ------------------------------------------------------------------
     def admit_wave(self, requests: List[Request]):
         """Prefill a wave of requests into free slots (right-padded)."""
-        pairs = self.sched.plan_wave(requests)
+        with span("engine.plan"):
+            pairs = self.sched.plan_wave(requests)
         if not pairs:
             return []
         wave = [r for _, r in pairs]
-        t0 = time.perf_counter()
         if self.sched.idle:
             # whole-batch prefill path
-            S = max(max(len(r.prompt) for r in wave), 1)
-            toks = np.zeros((self.B, S), np.int32)
-            lens = np.zeros((self.B,), np.int32)
-            for i, r in enumerate(wave):
-                toks[i, :len(r.prompt)] = r.prompt
-                lens[i] = len(r.prompt)
-            lens = np.maximum(lens, 1)
-            logits, self.cache = self._prefill(
-                self.params, jnp.asarray(toks), self.cache,
-                jnp.asarray(lens), None)
-            self.key, sub = jax.random.split(self.key)
-            first = np.asarray(sample_per_request(
-                logits[:len(wave)], sub, [r.sampling for r in wave]),
-                np.int32)
-            for i, r in enumerate(wave):
-                self._admit_slot(i, r, int(first[i]))
-            self.stats["waves"] += 1
+            with span("engine.wave", n=len(wave)):
+                with span("engine.feed"):
+                    S = max(max(len(r.prompt) for r in wave), 1)
+                    toks = np.zeros((self.B, S), np.int32)
+                    lens = np.zeros((self.B,), np.int32)
+                    for i, r in enumerate(wave):
+                        toks[i, :len(r.prompt)] = r.prompt
+                        lens[i] = len(r.prompt)
+                    lens = np.maximum(lens, 1)
+                    toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+                with span("engine.prefill"):
+                    logits, self.cache = self._prefill(
+                        self.params, toks, self.cache, lens, None)
+                with span("engine.sample"):
+                    self.key, sub = jax.random.split(self.key)
+                    first = sample_per_request(
+                        logits[:len(wave)], sub, [r.sampling for r in wave])
+                with span("engine.wait"):
+                    first = np.asarray(first, np.int32)
+                for i, r in enumerate(wave):
+                    self._admit_slot(i, r, int(first[i]))
+                self.stats["waves"] += 1
         else:
             # per-slot insertion
             for slot, r in pairs:
-                one = models.init_cache(self.cfg, 1, self.max_len)
-                toks = jnp.asarray([r.prompt], jnp.int32)
-                lens = jnp.asarray([len(r.prompt)], jnp.int32)
-                logits, one = self._prefill(self.params, toks, one, lens,
-                                            None)
-                self.cache = self._insert(self.cache, one, slot=slot)
-                self.key, sub = jax.random.split(self.key)
-                first = sample_per_request(logits[:1], sub, [r.sampling])
-                self._admit_slot(slot, r, int(np.asarray(first[0])))
-                self.stats["refills"] += 1
-        self.stats["prefill_s"] += time.perf_counter() - t0
+                with span("engine.refill", uid=r.uid, slot=slot,
+                          prompt_len=len(r.prompt)):
+                    with span("engine.cache"):
+                        one = models.init_cache(self.cfg, 1, self.max_len)
+                    with span("engine.feed"):
+                        toks = jnp.asarray([r.prompt], jnp.int32)
+                        lens = jnp.asarray([len(r.prompt)], jnp.int32)
+                    with span("engine.prefill"):
+                        logits, one = self._prefill(self.params, toks, one,
+                                                    lens, None)
+                    with span("engine.insert"):
+                        self.cache = self._insert(self.cache, one, slot=slot)
+                    with span("engine.sample"):
+                        self.key, sub = jax.random.split(self.key)
+                        first = sample_per_request(logits[:1], sub,
+                                                   [r.sampling])[0]
+                    with span("engine.wait"):
+                        first = int(np.asarray(first))
+                    self._admit_slot(slot, r, first)
+                    self.stats["refills"] += 1
         return wave
 
     # ------------------------------------------------------------------
@@ -151,25 +176,31 @@ class Engine:
         live = self.sched.live_slots()
         if not live:
             return
-        t0 = time.perf_counter()
-        tok = np.zeros((self.B,), np.int32)
-        for i in live:
-            tok[i] = self.sched.slot_req[i].output[-1]
-        logits, self.cache = self._decode(self.params, jnp.asarray(tok),
-                                          self.cache)
-        self.key, sub = jax.random.split(self.key)
-        nxt = np.asarray(sample_per_request(
-            logits[jnp.asarray(live)], sub,
-            [self.sched.slot_req[i].sampling for i in live]), np.int32)
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self.stats["steps"] += 1
-        for j, i in enumerate(live):
-            r = self.sched.slot_req[i]
-            r.output.append(int(nxt[j]))
-            self.stats["tokens_out"] += 1
-            hit_eos = r.eos_id >= 0 and r.output[-1] == r.eos_id
-            if self.sched.step(i, hit_eos=hit_eos):
-                r.done = True
+        with span("engine.decode", live=len(live)):
+            with span("engine.feed"):
+                tok = np.zeros((self.B,), np.int32)
+                for i in live:
+                    tok[i] = self.sched.slot_req[i].output[-1]
+                tok = jnp.asarray(tok)
+            with span("engine.step"):
+                logits, self.cache = self._decode(self.params, tok,
+                                                  self.cache)
+            with span("engine.sample"):
+                self.key, sub = jax.random.split(self.key)
+                nxt = sample_per_request(
+                    logits[jnp.asarray(live)], sub,
+                    [self.sched.slot_req[i].sampling for i in live])
+            with span("engine.wait"):
+                nxt = np.asarray(nxt, np.int32)
+            with span("engine.commit"):
+                self.stats["steps"] += 1
+                for j, i in enumerate(live):
+                    r = self.sched.slot_req[i]
+                    r.output.append(int(nxt[j]))
+                    self.stats["tokens_out"] += 1
+                    hit_eos = r.eos_id >= 0 and r.output[-1] == r.eos_id
+                    if self.sched.step(i, hit_eos=hit_eos):
+                        r.done = True
 
     # ------------------------------------------------------------------
     def run(self, requests: List[Request]) -> List[Request]:
@@ -183,7 +214,3 @@ class Engine:
                 pending = pending[len(wave):]
             self.decode_round()
         return submitted
-
-    def throughput(self) -> float:
-        tot = self.stats["prefill_s"] + self.stats["decode_s"]
-        return self.stats["tokens_out"] / tot if tot > 0 else 0.0
