@@ -328,8 +328,10 @@ def _layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int):
                                    cfg.d_model), jnp.bfloat16)}
     # KV caches are stored FUSED (B, T, Hkv*dh): the fused layout matches
     # the natural sharding of the kv projection output, so the cache
-    # scatter/gather needs no resharding under TP (the per-head reshape at
-    # the attend site factorizes the same tiling)
+    # scatter needs no resharding under TP. Decode writes each token into
+    # the unit's stacked cache in place and reads a layer in this layout as
+    # it lies (`_decode_attend`); a per-head (B, T, Hkv, dh) view of it
+    # would make XLA copy the layer out every round
     if kind == "xattn":
         nf = max(cfg.n_frontend_tokens, 1)
         return {"xk": jnp.zeros((batch, nf, hkv * dh), jnp.bfloat16),
@@ -371,12 +373,15 @@ def _cache_pos(cfg: ModelConfig, pos, max_len: int):
 
 
 def _apply_layer_cached(cfg: ModelConfig, kind: str, p: Params, x, cache,
-                        pos, *, enc_out=None, frontend=None,
+                        pos, *, layer=None, enc_out=None, frontend=None,
                         static_attn: bool = False):
-    """Sequence chunk (prefill, pos scalar 0) or single step (decode,
-    pos: (B,) per-sequence positions — continuous batching) w/ cache update.
+    """Sequence chunk (prefill: pos scalar 0, `layer` None) or single step
+    (decode: pos (B,) per-sequence positions — continuous batching) w/
+    cache update.
 
-    x: (B, S, d).
+    x: (B, S, d). In decode (`layer` given), self-attention k/v are the
+    unit's whole stacks (U, B, T, Hkv*dh) and this layer is entry `layer`
+    of them; every other leaf is this layer's own (see `_decode_layer`).
     """
     B, S, d = x.shape
     if kind == "rwkv":
@@ -423,19 +428,18 @@ def _apply_layer_cached(cfg: ModelConfig, kind: str, p: Params, x, cache,
 
     # self-attention with KV cache (+ optional enc-dec cross)
     h = L.apply_norm(cfg, p["ln1"], x)
-    if S > 1:
+    if layer is None:
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     else:
         positions = jnp.reshape(pos, (B, 1))
     q, k, v = L.attn_qkv(cfg, p["attn"], h, positions=positions)
-    max_len = cache["k"].shape[1]
-    win = max_len
     new_cache = dict(cache)
     kf = k.reshape(B, S, hkv * dh).astype(jnp.bfloat16)
     vf = v.reshape(B, S, hkv * dh).astype(jnp.bfloat16)
-    if S > 1:
+    if layer is None:
         # prefill from position 0 (right-padded prompts; pads are after the
         # valid tokens and get overwritten as decode advances per sequence)
+        win = cache["k"].shape[1]
         if cfg.attn_window and S > win:
             slots = (jnp.arange(S - win, S)) % win
             ck = cache["k"].at[:, slots].set(kf[:, -win:])
@@ -448,14 +452,13 @@ def _apply_layer_cached(cfg: ModelConfig, kind: str, p: Params, x, cache,
                               logit_softcap=cfg.attn_logit_softcap,
                               static=static_attn)
     else:
-        wpos = _cache_pos(cfg, pos, max_len)           # (B,)
-        bidx = jnp.arange(B)
-        ck = cache["k"].at[bidx, wpos].set(kf[:, 0])
-        cv = cache["v"].at[bidx, wpos].set(vf[:, 0])
+        # the token's k/v go in place at [layer, b, wpos[b]] of the stacks
+        T = cache["k"].shape[2]
+        at = (layer, jnp.arange(B), _cache_pos(cfg, pos, T))
+        ck = cache["k"].at[at].set(kf[:, 0])
+        cv = cache["v"].at[at].set(vf[:, 0])
         new_cache["k"], new_cache["v"] = ck, cv
-        valid = jnp.minimum(pos + 1, max_len)          # (B,)
-        o = _decode_attend(cfg, q, ck.reshape(B, max_len, hkv, dh),
-                           cv.reshape(B, max_len, hkv, dh), pos, valid)
+        o = _decode_attend(cfg, q, ck, cv, layer, pos)
     x = x + L.attn_out(p["attn"], o)
     if kind == "encdec":
         h = L.apply_norm(cfg, p["lnx"], x)
@@ -477,26 +480,40 @@ def _apply_layer_cached(cfg: ModelConfig, kind: str, p: Params, x, cache,
     return x + y, new_cache
 
 
-def _decode_attend(cfg: ModelConfig, q, ck, cv, pos, valid_len):
-    """Single-token attention over the cache, GQA-grouped (KV read once).
+def _decode_attend(cfg: ModelConfig, q, ks, vs, layer, pos):
+    """Single-token attention over entry `layer` of the stacked caches,
+    GQA-grouped (KV read once).
 
-    q: (B,1,Hq,dh); ck/cv: (B,T,Hkv,dh). Cache slot order may be a ring
-    rotation — softmax is permutation invariant and RoPE was applied at
-    write time, so ordering is irrelevant.
+    q: (B,1,Hq,dh); ks/vs: (U,B,T,Hkv*dh); pos: (B,). Keys k < min(pos+1, T)
+    are attended. Cache slot order may be a ring rotation — softmax is
+    permutation invariant and RoPE was applied at write time, so ordering
+    is irrelevant.
+
+    The layer is read where it lies: both dots take it as the plain
+    (B, T, Hkv*dh) operand it is stored as and contract over (or keep)
+    Hkv*dh, so XLA fuses the slice of the stack into the dot. The query
+    is held block-diagonally, (B, Hq, Hkv*dh) with head h's dh values in
+    the columns of its KV head and zeros elsewhere; the output keeps each
+    head's own columns. A per-head (B, T, Hkv, dh) view of the layer
+    would be a different tiling on the chip: XLA copies the layer out of
+    the stack, then again into a head-major layout, every round.
     """
     B, _, Hq, dh = q.shape
     Hkv = cfg.n_kv_heads
     G = max(1, Hq // Hkv)
-    qg = q.reshape(B, 1, Hkv, G, dh)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck,
+    T = ks.shape[2]
+    own = (jnp.arange(Hq)[:, None] // G
+           == jnp.arange(Hkv * dh)[None, :] // dh)        # (Hq, Hkv*dh)
+    qb = jnp.where(own, jnp.tile(q.reshape(B, Hq, dh), (1, 1, Hkv)), 0)
+    s = jnp.einsum("bhc,btc->bht", qb, ks[layer],
                    preferred_element_type=jnp.float32) / math.sqrt(dh)
     if cfg.attn_logit_softcap:
         s = cfg.attn_logit_softcap * jnp.tanh(s / cfg.attn_logit_softcap)
-    k_idx = jnp.arange(ck.shape[1])
-    mask = k_idx[None, :] < jnp.reshape(valid_len, (-1, 1))   # (B, T)
-    s = jnp.where(mask[:, None, None, None, :], s, L.NEG_INF)
+    mask = jnp.arange(T)[None, :] < jnp.minimum(pos + 1, T)[:, None]
+    s = jnp.where(mask[:, None, :], s, L.NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(cv.dtype), cv)
+    o = jnp.einsum("bht,btc->bhc", p.astype(vs.dtype), vs[layer])
+    o = jnp.where(own, o, 0).reshape(B, Hq, Hkv, dh).sum(2, dtype=o.dtype)
     return o.reshape(B, 1, Hq, dh)
 
 
@@ -549,9 +566,43 @@ def prefill(cfg: ModelConfig, params: Params, tokens, cache, frontend=None,
     return _mask_pad_logits(cfg, (x_last @ head)[:, 0]), new_cache
 
 
+_SELF_KV = ("k", "v")      # self-attention K/V: written and read in place
+_CROSS_KV = ("xk", "xv")   # cross-attention K/V: written by prefill only
+
+
+def _decode_layer(cfg: ModelConfig, kind: str, p: Params, x, stack, i, pos):
+    """One decode step of one layer on its unit position's cache `stack`,
+    every leaf of which has a leading unit axis; this layer is entry `i`.
+
+    What happens to a leaf follows from the leaf: self-attention k/v stay
+    whole and get the token written in place and read where they lie;
+    cross-attention xk/xv are read at `i`; any other leaf is a layer's
+    small recurrent state (rglru h/conv, rwkv state/sx_*), read at `i`
+    and written back whole at `i`.
+    """
+    one = {n: a if n in _SELF_KV else a[i] for n, a in stack.items()}
+    x, new = _apply_layer_cached(cfg, kind, p, x, one, pos, layer=i)
+    out = {}
+    for n, a in stack.items():
+        if n in _SELF_KV:
+            out[n] = new[n]
+        elif n in _CROSS_KV:
+            out[n] = a
+        else:
+            out[n] = lax.dynamic_update_index_in_dim(
+                a, new[n].astype(a.dtype), i, 0)
+    return x, out
+
+
 def decode_step(cfg: ModelConfig, params: Params, token, cache):
     """token: (B,) int32. Returns (logits (B,V), cache). Per-sequence
-    positions in cache["pos"] (continuous batching)."""
+    positions in cache["pos"] (continuous batching).
+
+    The layer scan carries the stacked per-unit cache, with the layer
+    index as its input: each layer writes its token's k/v into the carried
+    stack in place and attends over its entry where it lies
+    (`_decode_layer`, `_decode_attend`). Under a jit that donates `cache`
+    the step allocates no second cache and copies no layer of it."""
     unit, n_units, rem = unit_structure(cfg)
     x = params["embed"][token][:, None, :]
     pos = cache["pos"]                                   # (B,)
@@ -564,24 +615,26 @@ def decode_step(cfg: ModelConfig, params: Params, token, cache):
         pe = pe.at[:, 0::2].set(jnp.sin(ang)).at[:, 1::2].set(
             jnp.cos(ang[:, : (d - d // 2)]))
         x = x + pe[:, None, :].astype(x.dtype)
-    enc_out = cache.get("enc_out")
 
-    def unit_fn(x, pc):
-        unit_params, ucache = pc
-        new_uc = {}
+    def unit_fn(carry, xs):
+        x, ucache = carry
+        unit_params, i = xs
+        ucache = dict(ucache)
         for j, kind in enumerate(unit):
-            x, new_uc[f"u{j}"] = _apply_layer_cached(
-                cfg, kind, unit_params[f"u{j}"], x, ucache[f"u{j}"], pos,
-                enc_out=None, frontend=None)
-        return x, new_uc
+            x, ucache[f"u{j}"] = _decode_layer(
+                cfg, kind, unit_params[f"u{j}"], x, ucache[f"u{j}"], i, pos)
+        return (x, ucache), None
 
-    x, new_units = lax.scan(unit_fn, x, (params["units"], cache["units"]))
+    (x, new_units), _ = lax.scan(unit_fn, (x, cache["units"]),
+                                 (params["units"], jnp.arange(n_units)))
     new_cache = dict(cache)
     new_cache["units"] = new_units
     new_rem = {}
-    for j, kind in enumerate(rem):
-        x, new_rem[f"r{j}"] = _apply_layer_cached(
-            cfg, kind, params["rem"][f"r{j}"], x, cache["rem"][f"r{j}"], pos)
+    for j, kind in enumerate(rem):     # a stack of one layer each
+        one = jax.tree.map(lambda a: a[None], cache["rem"][f"r{j}"])
+        x, one = _decode_layer(cfg, kind, params["rem"][f"r{j}"], x, one, 0,
+                               pos)
+        new_rem[f"r{j}"] = jax.tree.map(lambda a: a[0], one)
     new_cache["rem"] = new_rem
     new_cache["pos"] = pos + 1
     x = L.apply_norm(cfg, params["final_norm"], x)

@@ -51,7 +51,8 @@ def jit_steps(cfg: ModelConfig):
     """The engine's two device programs, jitted: prefill(params, tokens,
     cache, prompt_lens, frontend) and decode(params, token, cache). Named
     functions, so the device trace names them `jit_prefill_step` and
-    `jit_decode_step`."""
+    `jit_decode_step`. Decode donates its cache: the step writes the new
+    K/V into it in place, and the caller's cache is gone after the call."""
     def prefill_step(params, tokens, cache, prompt_lens, frontend):
         return models.prefill(cfg, params, tokens, cache, frontend=frontend,
                               prompt_lens=prompt_lens)
@@ -59,7 +60,7 @@ def jit_steps(cfg: ModelConfig):
     def decode_step(params, token, cache):
         return models.decode_step(cfg, params, token, cache)
 
-    return jax.jit(prefill_step), jax.jit(decode_step)
+    return jax.jit(prefill_step), jax.jit(decode_step, donate_argnums=(2,))
 
 
 class Engine:

@@ -13,6 +13,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, so under several pytest workers only the
 worker that runs this file may load it.
 """
+import re
 from dataclasses import replace
 
 import jax
@@ -106,3 +107,51 @@ def test_engine_program_compiles_for_v5e(spec, program):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+_INSTR = re.compile(r"(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w\-]+)\(")
+
+
+def _materialised(hlo: str, shape: str):
+    """(computation, instruction, opcode) of every value of `shape` that
+    gets a buffer of its own: results of instructions outside the fused
+    computations, less parameters, tuple reads and bitcasts."""
+    comps, fused, cur = {}, set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line.strip())
+            fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    views = ("parameter", "get-tuple-element", "bitcast")
+    found = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = _INSTR.match(line)
+            if m and m.group(2) == shape and m.group(3) not in views:
+                found.append((name, m.group(1), m.group(3)))
+    return found
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "qwen2-0.5b"])
+def test_decode_updates_its_cache_in_place_on_v5e(spec, arch):
+    """The engine's decode program aliases its donated cache to its output,
+    and no whole layer of K or V gets a buffer: the token is written into
+    the stack in place and each layer is read where it lies."""
+    cfg = replace(get_config(arch), n_layers=2)
+    place = lambda tree: jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    _, decode = jit_steps(cfg)
+    cache = place(models.abstract_cache(cfg, B, T))
+    compiled = decode.lower(place(models.abstract_params(cfg)),
+                            spec((B,), jnp.int32), cache).compile()
+    kv = cache["units"]["u0"]
+    layer = B * T * cfg.n_kv_heads * cfg.d_head        # one layer's K
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv["k"].size * 2 + kv["v"].size * 2
+    if arch == "qwen3-1.7b":      # qwen2-0.5b's layer is 1 MB: the HLO says
+        assert mem.temp_size_in_bytes < layer * 2
+    whole = f"bf16[{B},{T},{cfg.n_kv_heads * cfg.d_head}]"
+    assert _materialised(compiled.as_text(), whole) == []

@@ -1,13 +1,21 @@
 """Serving engine: batched generation, continuous batching slot refill,
-sampler behavior."""
+decode on a donated cache, sampler behavior."""
+import math
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax import lax
 
 from repro.configs import ARCHS, smoke_config
 from repro import models
+from repro.models import lm
+from repro.models.layers import NEG_INF
 from repro.serving import (Engine, Request, SamplingParams, sample,
                            sample_per_request)
+from repro.serving.engine import jit_steps
 
 KEY = jax.random.PRNGKey(0)
 
@@ -42,21 +50,41 @@ def test_engine_continuous_batching_refill(dense_setup):
     assert len(done) == 5 and all(r.done for r in done)
 
 
+def _step_by_step(cfg, params, prompt, n):
+    """n greedy tokens from a manual prefill + decode loop."""
+    cache = models.init_cache(cfg, 1, 64)
+    lg, cache = models.prefill(cfg, params, jnp.asarray([prompt]), cache)
+    toks = [int(jnp.argmax(lg[0]))]
+    for _ in range(n - 1):
+        lg, cache = models.decode_step(cfg, params,
+                                       jnp.asarray([toks[-1]]), cache)
+        toks.append(int(jnp.argmax(lg[0])))
+    return toks
+
+
 def test_engine_greedy_matches_step_by_step(dense_setup):
     """Engine generation for one request == manual prefill+decode loop."""
     cfg, params = dense_setup
     prompt = [3, 1, 4, 1, 5]
     eng = Engine(cfg, params, batch_size=1, max_len=64)
     [req] = eng.run([Request(uid=0, prompt=prompt, max_new_tokens=4)])
+    assert req.output == _step_by_step(cfg, params, prompt, 4)
 
-    cache = models.init_cache(cfg, 1, 64)
-    lg, cache = models.prefill(cfg, params, jnp.asarray([prompt]), cache)
-    toks = [int(jnp.argmax(lg[0]))]
-    for _ in range(3):
-        lg, cache = models.decode_step(cfg, params,
-                                       jnp.asarray([toks[-1]]), cache)
-        toks.append(int(jnp.argmax(lg[0])))
-    assert req.output == toks
+
+def test_engine_decode_consumes_its_cache(dense_setup):
+    """Each decode round donates the engine's cache to the step (its
+    leaves are deleted) and the tokens stay the step-by-step loop's."""
+    cfg, params = dense_setup
+    prompt = [3, 1, 4, 1, 5]
+    eng = Engine(cfg, params, batch_size=1, max_len=64)
+    req = Request(uid=0, prompt=prompt, max_new_tokens=4)
+    eng.admit_wave([req])
+    for _ in range(2):
+        before = jax.tree.leaves(eng.cache)
+        eng.decode_round()
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in jax.tree.leaves(eng.cache))
+    assert req.output == _step_by_step(cfg, params, prompt, 3)
 
 
 def test_engine_eos_stops(dense_setup):
@@ -179,3 +207,79 @@ def test_sampler_topp_restricts():
         t = int(sample(logits, jax.random.PRNGKey(seed),
                        SamplingParams(temperature=1.0, top_p=0.5))[0])
         assert t == 0
+
+
+# -------- decode writes and reads its cache in place ----------
+
+def _per_head_attend(cfg, q, ks, vs, layer, pos):
+    """Decode attention as it read the cache before: through a per-head
+    (B, T, Hkv, dh) view of the layer."""
+    B, _, hq, dh = q.shape
+    hkv = cfg.n_kv_heads
+    k = ks[layer].reshape(B, -1, hkv, dh)
+    v = vs[layer].reshape(B, -1, hkv, dh)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(B, 1, hkv, hq // hkv, dh),
+                   k, preferred_element_type=jnp.float32) / math.sqrt(dh)
+    if cfg.attn_logit_softcap:
+        s = cfg.attn_logit_softcap * jnp.tanh(s / cfg.attn_logit_softcap)
+    T = k.shape[1]
+    valid = jnp.arange(T)[None, :] < jnp.minimum(pos + 1, T)[:, None]
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(B, 1, hq, dh)
+
+
+def _copied_layer(decode_layer):
+    """A layer's cache copied out of the stack, stepped, copied back."""
+    def step(cfg, kind, p, x, stack, i, pos):
+        one = jax.tree.map(lambda a: a[i][None], stack)
+        x, one = decode_layer(cfg, kind, p, x, one, 0, pos)
+        return x, jax.tree.map(
+            lambda a, n: lax.dynamic_update_index_in_dim(a, n[0], i, 0),
+            stack, one)
+    return step
+
+
+@pytest.mark.parametrize("arch,window", [
+    ("qwen3-1.7b", 0), ("qwen1.5-0.5b", 8), ("recurrentgemma-2b", 0),
+    ("rwkv6-7b", 0), ("whisper-tiny", 0), ("llama-3.2-vision-11b", 0)])
+def test_donated_decode_matches_the_copying_formulation(arch, window,
+                                                        monkeypatch):
+    """The engine's donated decode gives the logits and cache, bit for bit,
+    of decode as it was before: each layer's cache copied out and back,
+    attention through a per-head view. Slots at different positions, one
+    dead (a wave's padding row); a window of 8 wraps its ring."""
+    cfg = smoke_config(ARCHS[arch])
+    if window:
+        cfg = replace(cfg, attn_window=window)
+    params = models.init_params(cfg, KEY)
+    B, T = 4, 32
+    lens = jnp.asarray([12, 5, 9, 1], jnp.int32)
+    toks = jax.random.randint(KEY, (B, 12), 1, cfg.vocab_size)
+    toks = toks.at[3].set(0)
+    fe = None
+    if models.needs_frontend(cfg):
+        fe = jax.random.normal(KEY, (B, 8, cfg.d_model), jnp.bfloat16)
+    lg, cache = jax.jit(lambda p, t, c, f, n: models.prefill(
+        cfg, p, t, c, frontend=f, prompt_lens=n))(
+        params, toks, models.init_cache(cfg, B, T), fe, lens)
+
+    def rounds(step):
+        lg_, cache_ = lg, cache
+        for _ in range(12):
+            tok = jnp.argmax(lg_, -1).astype(jnp.int32)
+            given = jax.tree.map(jnp.copy, cache_)
+            lg_, cache_ = step(params, tok, given)
+            yield given, lg_, cache_
+
+    with monkeypatch.context() as m:
+        m.setattr(lm, "_decode_attend", _per_head_attend)
+        m.setattr(lm, "_decode_layer", _copied_layer(lm._decode_layer))
+        want = [(lg_, c) for _, lg_, c in rounds(jax.jit(
+            lambda p, t, c: models.decode_step(cfg, p, t, c)))]
+    _, decode = jit_steps(cfg)
+    for (given, got_lg, got), (want_lg, want_c) in zip(rounds(decode), want):
+        assert all(a.is_deleted() for a in jax.tree.leaves(given))
+        np.testing.assert_array_equal(np.asarray(got_lg), np.asarray(want_lg))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want_c)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
