@@ -7,6 +7,10 @@ everything it needs comes from files named after it:
                               reference (references/<reference>.py) and
                               program mapping (references/<program>.py)
                               to use
+  references/<reference>.py   the plain model, and `work(conf)`: the
+                              operations and bytes each prefill call and
+                              decode round needs (see work.py), which
+                              the rooflines and `step_mfu` read
   traffic/<traffic>.json      the mix, read by traffic.py
   cells/<workload>.json       slots, max_len, arrival rate, and the limit
                               of the correctness comparison
@@ -186,14 +190,14 @@ class Driver:
     """The loop of `Engine.run`, with requests admitted as they come due,
     and a timestamp on every token when the call that made it returns."""
 
-    def __init__(self, engine, Request, mix, seed, vocab, rate, shape,
+    def __init__(self, engine, Request, mix, seed, vocab, rate, counts,
                  clock=time.perf_counter):
         import jax
         self.jax = jax
         self.engine = engine
         self.Request = Request
         self.clock = clock
-        self.shape = shape
+        self.counts = counts
         self.backlog = mix["arrivals"] == "backlog"
         self.stream = traffic_gen.stream(mix, seed, vocab, rate)
         self.next_gen = None
@@ -253,7 +257,7 @@ class Driver:
                 rec.tokens.append(t1)
                 lens.append(len(rec.req.prompt))
                 self._finish(rec)
-            f, b = work.prefill(self.shape, lens)
+            f, b = self.counts.prefill(lens)
             self.calls.append(Call(kind, idx, t0, t1, len(lens), f, b))
         live = [r for r in eng.slot_req if r is not None]
         if live:
@@ -267,7 +271,7 @@ class Driver:
                 rec = self.recs[r.uid]
                 rec.tokens.append(t1)
                 self._finish(rec)
-            f, b = work.decode_round(self.shape, ctx)
+            f, b = self.counts.decode_round(ctx)
             self.calls.append(Call("decode", idx, t0, t1, len(live), f, b))
         elif not self.waiting:
             wait = min(self.next_due(), until) - clock()
@@ -374,6 +378,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     conf, mix, cp = cell.conf, cell.mix, cell.params
     ref = importlib.import_module(f"references.{conf['reference']}")
+    if not callable(getattr(ref, "work", None)):
+        raise SystemExit(f"perfbench: the reference module {ref.__name__} "
+                         "has no work(conf), the count of the operations "
+                         "and bytes each call needs (see work.py)")
+    counts = ref.work(conf)
     prog = importlib.import_module(f"references.{conf['program']}")
     cfg = prog.program_config(conf)
     seeds = sub_seeds(seed)
@@ -400,9 +409,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     jax.block_until_ready(engine.cache)
     t_warm = time.perf_counter()
 
-    shape = work.shape(conf)
     drv = Driver(engine, Request, mix, seed, conf["vocab_size"],
-                 cp.get("rate"), shape)
+                 cp.get("rate"), counts)
     drv.t_sched = time.perf_counter()
     ramp = mix.get("ramp", "refill_every_slot")
     first_wave = None

@@ -22,13 +22,21 @@ activations rounded to bfloat16 wherever a bfloat16 program keeps them
 (linear and attention outputs, norms, the residual stream, attention
 probabilities), with float32 accumulation and float32 softmax, so that
 its gap shows what rounding alone does at the served precision.
+
+`work(conf)` counts the operations and bytes each prefill call and decode
+round of this model needs, for the rooflines and `step_mfu`: one
+description of the block, `dims(conf)`, serves the reference, the
+program mapping (qwen_program.py) and the counts.
 """
 from __future__ import annotations
 
 import math
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from work import causal_attention_flops
 
 HIGHEST = jax.lax.Precision.HIGHEST
 SCORE_STD = 1.0
@@ -45,6 +53,49 @@ def dims(conf: dict) -> dict:
             or bool(conf.get("attention_bias")),
             "qk_norm": conf["model_type"] == "qwen3",
             "eps": conf["rms_norm_eps"], "theta": float(conf["rope_theta"])}
+
+
+class Work:
+    """Operations and bytes a call of the served model needs, from
+    `dims(conf)` and the lengths alone (the rule is work.py's): every
+    weight read once per call, the K/V of each sequence's actual context,
+    K/V written once. The embedding lookup is free; the output head (tied
+    to the embedding) is a matmul of d x V per token whose logits are
+    needed: the last token of each prompt, each decoded token."""
+
+    def __init__(self, conf: dict):
+        m = dims(conf)
+        L, d, hq, hkv, dh, ff, V = (m["L"], m["d"], m["hq"], m["hkv"],
+                                    m["dh"], m["ff"], m["V"])
+        layer_mat = d * hq * dh + 2 * d * hkv * dh + hq * dh * d + 3 * d * ff
+        layer_small_bytes = (2 * d * 4                      # two norm scales
+                             + ((hq + 2 * hkv) * dh * 2 if m["bias"] else 0)
+                             + (2 * dh * 4 if m["qk_norm"] else 0))
+        self.matmul_params = L * layer_mat              # per token, no head
+        self.weight_bytes = (L * (2 * layer_mat + layer_small_bytes)
+                             + 2 * V * d + 4 * d)       # embedding, norm
+        self.kv_bytes_per_token = L * 2 * hkv * dh * 2  # bf16 K and V
+        self.head_flops = 2.0 * d * V
+        self.attn_per_pair = 4.0 * L * hq * dh          # QK^T and PV
+
+    def decode_round(self, ctx_lens: Sequence[int]) -> Tuple[float, float]:
+        n = len(ctx_lens)
+        flops = n * (2.0 * self.matmul_params + self.head_flops)
+        flops += sum(self.attn_per_pair * c for c in ctx_lens)
+        bytes_ = self.weight_bytes + sum(ctx_lens) * self.kv_bytes_per_token
+        return flops, float(bytes_)
+
+    def prefill(self, prompt_lens: Sequence[int]) -> Tuple[float, float]:
+        flops = 0.0
+        for p in prompt_lens:
+            flops += 2.0 * self.matmul_params * p + self.head_flops
+            flops += causal_attention_flops(self.attn_per_pair, p)
+        bytes_ = (self.weight_bytes
+                  + sum(prompt_lens) * self.kv_bytes_per_token)
+        return flops, float(bytes_)
+
+
+work = Work     # the entry point the harness looks up by name
 
 
 def init_weights(conf: dict, key) -> dict:

@@ -5,6 +5,7 @@ import pytest
 import harness
 import reduce_trace as rt
 import work
+from references import qwen
 
 from metrics import (decode_device_ms, decode_roofline, idle_share,
                      refill_device_ms, refill_roofline, step_mfu)
@@ -13,7 +14,8 @@ from metrics import (decode_device_ms, decode_roofline, idle_share,
 # head, ff 8, one layer, vocabulary 10.
 CONF = {"hidden_size": 4, "num_attention_heads": 2, "num_key_value_heads": 1,
         "head_dim": 2, "intermediate_size": 8, "num_hidden_layers": 1,
-        "vocab_size": 10, "model_type": "qwen3"}
+        "vocab_size": 10, "model_type": "qwen3", "rms_norm_eps": 1e-6,
+        "rope_theta": 10000.0}
 PEAKS = {"flops": 1000.0, "bytes_per_s": 100.0}
 S = 1e9          # trace nanoseconds per second
 
@@ -36,17 +38,17 @@ def test_idle_gaps_are_attributed_to_the_host_span_they_fell_in():
 
 
 def test_model_counts_by_hand():
-    s = work.shape(CONF)
+    s = qwen.work(CONF)
     # per layer: q 4x4, k and v 4x2 each, o 4x4, three MLP mats 4x8
-    assert s["matmul_params"] == 16 + 16 + 16 + 96
+    assert s.matmul_params == 16 + 16 + 16 + 96
     # bf16 mats, two f32 norms of 4, two f32 q/k norms of 2, bf16
     # embedding 10x4, f32 final norm of 4
-    assert s["weight_bytes"] == 2 * 144 + 32 + 16 + 80 + 16
-    assert s["kv_bytes_per_token"] == 8
-    f, b = work.decode_round(s, [3, 5])
+    assert s.weight_bytes == 2 * 144 + 32 + 16 + 80 + 16
+    assert s.kv_bytes_per_token == 8
+    f, b = s.decode_round([3, 5])
     assert f == 2 * (2 * 144 + 2 * 4 * 10) + 4 * 2 * 2 * (3 + 5)
     assert b == 432 + 8 * 8
-    f, b = work.prefill(s, [3])
+    f, b = s.prefill([3])
     assert f == 2 * 144 * 3 + 2 * 4 * 10 + 4 * 2 * 2 * (1 + 2 + 3)
     assert b == 432 + 3 * 8
 
@@ -64,9 +66,9 @@ def _call(kind, i, n, fb):
 
 
 def test_device_time_goes_by_host_span_and_rooflines_by_hand():
-    s = work.shape(CONF)
-    dec = work.decode_round(s, [3, 5])          # 864 flops, 496 bytes
-    ref = work.prefill(s, [3])                  # 1040 flops, 456 bytes
+    s = qwen.work(CONF)
+    dec = s.decode_round([3, 5])                # 864 flops, 496 bytes
+    ref = s.prefill([3])                        # 1040 flops, 456 bytes
     calls = [_call("decode", 0, 2, dec), _call("refill", 1, 1, ref),
              _call("decode", 2, 2, dec)]
     spans = [("pb.decode#0", 0 * S, 10 * S), ("pb.refill#1", 10 * S, 20 * S),
@@ -91,8 +93,7 @@ def test_device_time_goes_by_host_span_and_rooflines_by_hand():
 
 
 def test_a_decode_reading_only_the_live_context_is_at_most_100_percent():
-    s = work.shape(CONF)
-    f, b = work.decode_round(s, [3, 5])
+    f, b = qwen.work(CONF).decode_round([3, 5])
     need = b / PEAKS["bytes_per_s"]      # weights once + live K/V only
     calls = [_call("decode", 0, 2, (f, b))]
     spans = [("pb.decode#0", 0, 10 * S)]
